@@ -2,13 +2,14 @@
 
 Times each runtime-facing kernel — flash attention (the `use_pallas`
 serving forward), the CKA Gram-term probe (SimFreeze's drift metric) and
-the RWKV wkv recurrence — in interpret mode next to its `ref.py` oracle,
+the RWKV wkv recurrence — next to its `ref.py` oracle,
 and records the parity error alongside, so the bench artifact tracks
 both the per-op cost *and* that the kernels still agree with the math
-they replace. On CPU the interpret-mode numbers are emulation costs, not
-device timings — the column exists for trajectory tracking (a kernel
-whose interpret time explodes got structurally slower) and becomes a
-real device measurement on TPU (`bootstrap(platform=...)`).
+they replace. The kernels run in Pallas interpret mode on CPU and compiled
+on TPU (`repro.kernels.resolve_interpret`; the artifact's `interpret`
+field records which). On CPU the numbers are emulation costs, not device
+timings — the column exists for trajectory tracking (a kernel whose
+interpret time explodes got structurally slower).
 
     PYTHONPATH=src python benchmarks/kernels_micro.py [--iters 5]
 
@@ -28,6 +29,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import jax
 import numpy as np
+
+from repro.kernels import resolve_interpret
 
 SCHEMA_VERSION = 1
 DEFAULT_OUT = os.path.abspath(
@@ -109,7 +112,7 @@ def run(iters: int = 5, seed: int = 0) -> Dict:
         "schema_version": SCHEMA_VERSION, "suite": "kernels_micro",
         "seed": seed, "created_unix": int(time.time()),
         "jax_version": jax.__version__,
-        "interpret": True, "cells": cells,
+        "interpret": resolve_interpret(), "cells": cells,
     }
 
 

@@ -11,33 +11,15 @@ enough:
 """
 from __future__ import annotations
 
-import inspect
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from repro.optim import compression
-
-try:  # jax >= 0.6 exposes shard_map at the top level (check_vma kwarg)
-    from jax import shard_map as _shard_map
-except ImportError:  # jax 0.4.x keeps it in experimental (check_rep kwarg)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SM_PARAMS = set(inspect.signature(_shard_map).parameters)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True, **kw):
-    """Version-tolerant `shard_map`: translates the modern ``check_vma``
-    kwarg to 0.4.x's ``check_rep`` (same meaning, renamed upstream)."""
-    if "check_vma" in _SM_PARAMS:
-        kw["check_vma"] = check_vma
-    elif "check_rep" in _SM_PARAMS:
-        kw["check_rep"] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
 
 
 def sync_grads_shard_map(mesh: Mesh, grads, *, axis: str = "data",

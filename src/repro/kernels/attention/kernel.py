@@ -79,7 +79,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
                            softcap: float = 0.0, bq: int = 512, bk: int = 512,
-                           interpret: bool = True):
+                           interpret: bool):
     """q: [B, Sq, Hq, hd]; k/v: [B, Sk, Hkv, hd] -> [B, Sq, Hq, hd]."""
     B, Sq, Hq, hd = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]

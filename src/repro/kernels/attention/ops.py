@@ -1,11 +1,15 @@
-"""Jitted wrapper for the flash-attention kernel with shape padding."""
+"""Jitted wrapper for the flash-attention kernel with shape padding. The
+kernel runs compiled on TPU and in Pallas interpret mode on CPU
+(`repro.kernels.resolve_interpret`); pass `interpret=` to force either."""
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.attention.kernel import flash_attention_pallas
 
 
@@ -13,7 +17,7 @@ from repro.kernels.attention.kernel import flash_attention_pallas
                                    "interpret"))
 def flash_attention(q, k, v, causal: bool = True, window: int = 0,
                     softcap: float = 0.0, bq: int = 512, bk: int = 512,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """Padding-safe wrapper: pads Sq/Sk up to block multiples (padded kv
     positions are masked out by the causal test since they sit beyond the
     real sequence)."""
@@ -30,5 +34,5 @@ def flash_attention(q, k, v, causal: bool = True, window: int = 0,
         v = jnp.pad(v, ((0, 0), (0, pk), (0, 0), (0, 0)))
     out = flash_attention_pallas(q, k, v, causal=causal, window=window,
                                  softcap=softcap, bq=bq, bk=bk,
-                                 interpret=interpret)
+                                 interpret=resolve_interpret(interpret))
     return out[:, :Sq]

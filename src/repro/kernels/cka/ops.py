@@ -1,13 +1,16 @@
 """Jitted wrapper for the CKA Gram-term kernel: centering, padding to tile
-multiples, and the CKA ratio. `interpret=True` on CPU (kernel-body
-semantics validated against ref.py); on TPU pass interpret=False."""
+multiples, and the CKA ratio. The kernel runs compiled on TPU and in
+Pallas interpret mode on CPU (`repro.kernels.resolve_interpret`); pass
+`interpret=` to force either."""
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.cka.kernel import cka_terms_pallas
 
 
@@ -25,7 +28,7 @@ def _prepare(x: jax.Array, bn: int, bk: int) -> jax.Array:
 
 @partial(jax.jit, static_argnames=("bn", "bk", "interpret"))
 def cka_terms(x: jax.Array, y: jax.Array, bn: int = 128, bk: int = 512,
-              interpret: bool = True):
+              interpret: Optional[bool] = None):
     """Returns (hsic, sqrt(kk), sqrt(ll)) matching core.cka conventions."""
     xp = _prepare(x, bn, bk)
     yp = _prepare(y, bn, bk)
@@ -36,11 +39,12 @@ def cka_terms(x: jax.Array, y: jax.Array, bn: int = 128, bk: int = 512,
     n = max(xp.shape[0], yp.shape[0])
     xp = jnp.pad(xp, ((0, n - xp.shape[0]), (0, 0)))
     yp = jnp.pad(yp, ((0, n - yp.shape[0]), (0, 0)))
-    hsic, kk, ll = cka_terms_pallas(xp, yp, bn=bn, bk=bk, interpret=interpret)
+    hsic, kk, ll = cka_terms_pallas(xp, yp, bn=bn, bk=bk,
+                                    interpret=resolve_interpret(interpret))
     return hsic, jnp.sqrt(kk), jnp.sqrt(ll)
 
 
 def cka(x: jax.Array, y: jax.Array, bn: int = 128, bk: int = 512,
-        interpret: bool = True) -> jax.Array:
+        interpret: Optional[bool] = None) -> jax.Array:
     hsic, nx, ny = cka_terms(x, y, bn=bn, bk=bk, interpret=interpret)
     return hsic / jnp.maximum(nx * ny, 1e-12)

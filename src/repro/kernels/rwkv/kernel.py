@@ -55,7 +55,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_scr, *,
     o_ref[0] = o.astype(o_ref.dtype)
 
 
-def wkv_pallas(r, k, v, logw, u, *, bt: int = 512, interpret: bool = True):
+def wkv_pallas(r, k, v, logw, u, *, bt: int = 512, interpret: bool):
     """r/k/v/logw: [B, T, H, n]; u: [H, n]. Returns o [B, T, H, n] fp32."""
     B, T, H, n = r.shape
     bt = min(bt, T)

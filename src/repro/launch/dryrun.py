@@ -146,7 +146,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, *,
     if print_analysis:
         log.info("[%s x %s x %s] memory_analysis: %s",
                  arch, shape_name, mesh_name, mem)
-        ca = RA.cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         log.info("[%s x %s x %s] cost_analysis: %s",
                  arch, shape_name, mesh_name,
                  {k: ca[k] for k in ("flops", "bytes accessed") if k in ca})
@@ -269,7 +269,7 @@ def _probe_costs(arch, shape_name, cfg, shape, mesh, policy, opt_cfg,
                 lambda p, c, t, i: T.lm_decode(p, cfg, t, c, i),
                 donate_argnums=(1,)).lower(
                     params_sds, cache_sds, tok_sds, pos_sds).compile()
-    ca = RA.cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     stats = RA.parse_collectives(compiled.as_text())
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes": float(ca.get("bytes accessed", 0.0)),

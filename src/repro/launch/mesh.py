@@ -4,15 +4,12 @@ XLA_FLAGS=--xla_force_host_platform_device_count before first jax init)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def _mesh(shape, axes):
-    # jax >= 0.5 takes axis_types; 0.4.x predates AxisType entirely.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -4,11 +4,12 @@ One idempotent entry point, `bootstrap()`, to be called before the first
 jax dispatch: it pins the jax platform, applies the GPU latency-hiding
 XLA scheduler flags (no-ops elsewhere), optionally fans the CPU backend
 out into several host devices (`--xla_force_host_platform_device_count`,
-useful for mesh dry-runs on a laptop), and silences the CPU
-buffer-donation warning the compiled hot path would otherwise emit per
-program. Library code never calls this — sessions must work under
-whatever platform the embedder configured — which is why it lives under
-`repro.launch` next to the other entry-point helpers.
+useful for mesh dry-runs on a laptop), turns on the persistent
+compilation cache, and silences the CPU buffer-donation warning the
+compiled hot path would otherwise emit per program. Library code never
+calls this — sessions must work under whatever platform the embedder
+configured — which is why it lives under `repro.launch` next to the
+other entry-point helpers.
 """
 from __future__ import annotations
 
@@ -19,6 +20,12 @@ _GPU_XLA_FLAGS = (
     "--xla_gpu_enable_latency_hiding_scheduler=true",
     "--xla_gpu_enable_highest_priority_async_stream=true",
 )
+
+#: The compile cache's home when JAX_COMPILATION_CACHE_DIR is unset: one
+#: fixed directory inside the checkout (listed in .gitignore). The path
+#: is part of every cache key, so it must not vary between runs.
+CACHE_DIR = os.path.abspath(os.path.join(
+    os.path.dirname(__file__), "..", "..", "..", ".jax_cache"))
 
 _bootstrapped = False
 
@@ -49,28 +56,23 @@ def set_platform(platform: str) -> None:
     jax.config.update("jax_platform_name", platform)
 
 
-def enable_compile_cache(cache_dir: str = None) -> None:
-    """Point XLA's persistent compilation cache at `cache_dir` (default:
-    $EDGEOL_XLA_CACHE, else ~/.cache/edgeol/xla; pass "" via either
-    route to disable). Must run before the first jax compile.
+def enable_compile_cache() -> None:
+    """Turn on XLA's persistent compilation cache. Where
+    JAX_COMPILATION_CACHE_DIR is set, jax reads the directory from it and
+    nothing here overrides it; otherwise the cache lives at `CACHE_DIR`.
+    Must run before the first jax compile.
 
     This is the cross-process half of the compiled hot path's
     initialization story (DESIGN.md §12): within one process, sessions
     share programs through the registries in runtime/train_loop.py; with
     the disk cache, a fresh process (the CI sweep, a relaunched edge
-    runtime) deserializes yesterday's programs in tens of milliseconds
-    instead of re-paying multi-second XLA compiles — the same
-    "amortize system initialization" premise LazyTune applies to
-    in-process retraces (paper §IV-B)."""
+    runtime) deserializes earlier programs instead of re-paying XLA
+    compiles — the same "amortize system initialization" premise LazyTune
+    applies to in-process retraces (paper §IV-B)."""
     import jax
 
-    if cache_dir is None:
-        cache_dir = os.environ.get(
-            "EDGEOL_XLA_CACHE",
-            os.path.join(os.path.expanduser("~"), ".cache", "edgeol", "xla"))
-    if not cache_dir:
-        return
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
     # default thresholds skip small/fast programs; an edge deployment
     # wants every program persisted — the point is a compile-free restart
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
@@ -78,10 +80,11 @@ def enable_compile_cache(cache_dir: str = None) -> None:
 
 
 def bootstrap(platform: str = None, host_devices: int = None,
-              enable_x64: bool = False, cache_dir: str = None) -> None:
+              enable_x64: bool = False) -> None:
     """Idempotent process setup for entry points (benchmarks, examples,
-    microbenches). `platform` defaults to the EDGEOL_PLATFORM environment
-    variable when set, else jax's own default backend."""
+    microbenches, chip_smoke.py). `platform` defaults to the
+    EDGEOL_PLATFORM environment variable when set, else jax's own default
+    backend."""
     global _bootstrapped
     if _bootstrapped:
         return
@@ -96,7 +99,7 @@ def bootstrap(platform: str = None, host_devices: int = None,
     platform = platform or os.environ.get("EDGEOL_PLATFORM")
     if platform:
         set_platform(platform)
-    enable_compile_cache(cache_dir)
+    enable_compile_cache()
     if enable_x64:
         import jax
 

@@ -136,18 +136,9 @@ class RooflineReport:
         }
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """`compiled.cost_analysis()` as a flat dict across jax versions
-    (0.4.x returns a one-element list of dicts, newer jax a dict)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def analyze(compiled, *, arch: str, shape: str, mesh_name: str, chips: int,
             model_flops: float) -> RooflineReport:
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     flops = float(cost.get("flops", 0.0))
     bytes_acc = float(cost.get("bytes accessed", 0.0))
     stats = parse_collectives(compiled.as_text())

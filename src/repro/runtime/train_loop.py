@@ -189,10 +189,8 @@ class TrainStepCache:
                     lambda p: make_optimizer_state(
                         self.model, self.opt_cfg, p),
                     params)
-                from repro.roofline.analysis import cost_analysis_dict
-
                 lowered = step.lower(params, opt_state, example_batch)
-                cost = cost_analysis_dict(lowered.compile())
+                cost = lowered.compile().cost_analysis()
                 val = _FLOPS[key] = float(cost.get("flops", 0.0))
             self._flops[plan] = val
         return self._flops[plan]

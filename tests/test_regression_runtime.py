@@ -1,9 +1,13 @@
 """Fixed-seed regression tests pinning the decomposed runtime to the
 pre-refactor monolith.
 
-``tests/data/golden_runtime.json`` was captured by running the original
-single-method ``ContinualRuntime.run`` (commit 780bab6's runtime, after
-the jax-0.4.x compat fixes) on small fixed-seed configs. The decomposed
+``tests/data/golden_runtime.json`` was first captured on jax 0.4.37 by
+running the original single-method ``ContinualRuntime.run`` (commit
+780bab6's runtime) on small fixed-seed configs. It was re-captured on jax
+0.9.0, where the decomposed runtime of commit 716250d and the current
+runtime produce identical figures: XLA's CPU numerics and FLOP counts
+moved between the jax versions, the runtime's behaviour did not. The
+decomposed
 scheduler/executor/ledger/server runtime must reproduce every recorded
 figure — accuracy trace, round/recompile counts, and the full CostLedger
 breakdown — with micro-batching disabled.
