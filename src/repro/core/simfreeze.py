@@ -24,6 +24,7 @@ import numpy as np
 
 from repro.core.cka import cka as _cka
 from repro.core.freeze_plan import FreezePlan, LayerFreezePlan
+from repro.obs.host import count, span
 
 
 @dataclass
@@ -70,8 +71,8 @@ class SimFreeze:
         (paper: 'the first arrived training data batch')."""
         self.reference_params = reference_params
         self.probe_batch = probe_batch
-        self._ref_feats = [np.asarray(f, np.float32)
-                           for f in self.features_fn(reference_params, probe_batch)]
+        self._ref_feats = self._reference_features(reference_params,
+                                                   probe_batch)
         for h in self.state.cka_history:
             h.clear()
 
@@ -90,14 +91,29 @@ class SimFreeze:
         return float(_cka(feats[unit], self._ref_feats[unit],
                                  use_kernel=self.cfg.use_kernel))
 
+    def _reference_features(self, params, probe_batch) -> List[np.ndarray]:
+        """The reference model's features on the probe batch, on the host
+        (one pull per unit)."""
+        with span("cka/reference"):
+            feats = [np.asarray(f, np.float32)
+                     for f in self.features_fn(params, probe_batch)]
+        count("host_syncs", len(feats), site="cka_reference")
+        return feats
+
     def _all_cka(self, params) -> List[float]:
-        feats = self.features_fn(params, self.probe_batch)
-        vals = []
-        for f, rf in zip(feats, self._ref_feats):
-            vals.append(float(_cka(f, rf, use_kernel=self.cfg.use_kernel)))
-            self.state.cka_flops += 2.0 * np.prod(np.shape(f)) * min(
-                np.shape(np.asarray(f).reshape(-1, np.shape(f)[-1]))[0],
-                np.shape(f)[-1])
+        with span("cka/pass"):
+            with span("cka/features"):
+                feats = self.features_fn(params, self.probe_batch)
+            vals = []
+            for f, rf in zip(feats, self._ref_feats):
+                vals.append(float(_cka(f, rf,
+                                       use_kernel=self.cfg.use_kernel)))
+                self.state.cka_flops += 2.0 * np.prod(np.shape(f)) * min(
+                    np.shape(np.asarray(f).reshape(-1, np.shape(f)[-1]))[0],
+                    np.shape(f)[-1])
+        # per unit: the CKA value, and the feature map to read its shape
+        count("host_syncs", len(vals), site="cka_unit")
+        count("host_syncs", len(vals), site="cka_shape")
         return vals
 
     def _freeze_pass(self, params) -> bool:
@@ -127,8 +143,8 @@ class SimFreeze:
                     for i in range(self.num_units)
                     if st.frozen[i] and st.cka_history[i]}
         self.probe_batch = new_probe_batch
-        self._ref_feats = [np.asarray(f, np.float32) for f in
-                           self.features_fn(self.reference_params, new_probe_batch)]
+        self._ref_feats = self._reference_features(self.reference_params,
+                                                   new_probe_batch)
         vals = self._all_cka(params)
         changed = False
         for i in range(self.num_units):

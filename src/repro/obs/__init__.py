@@ -1,6 +1,7 @@
 """repro.obs — the runtime's observability layer (DESIGN.md §14).
 
-Three cooperating pieces, all optional and all off by default:
+Three cooperating pieces on the modelled timeline, all optional and all
+off by default:
 
 - `trace` — a `Tracer` recording structured spans/instants on the
   *modeled* timeline (rounds, preemption segments, swaps, syncs, probes,
@@ -14,6 +15,11 @@ Three cooperating pieces, all optional and all off by default:
   the validating loader CI uses; `benchmarks/trace_report.py` renders the
   human summary (utilization timeline, round Gantt, slowest segments).
 
+`host` is separate and always on: host spans (`span`) on the program's
+own clock, mirrored as `jax.profiler` annotations, and counters
+(`count`, `observe`) of syncs, copies, compiles and request waits; a
+session's delta lands in `RunResult.host` (DESIGN.md §14, "Host spans").
+
 `TelemetrySpec` (spec.py) is the JSON-round-trippable config knob
 (`RuntimeConfig.telemetry`); `Telemetry` (telemetry.py) is the live
 bundle a session carries. `log` is the structured-logging bootstrap
@@ -22,6 +28,7 @@ bundle a session carries. `log` is the structured-logging bootstrap
 from repro.obs.export import (chrome_trace, chrome_tracks,
                               events_from_chrome, load_chrome_trace,
                               read_jsonl, write_chrome_trace, write_jsonl)
+from repro.obs import host
 from repro.obs.log import configure_logging, get_logger
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
 from repro.obs.spec import TelemetrySpec
@@ -33,7 +40,7 @@ __all__ = [
     "Counter", "DEVICE_TIME_CATS", "Gauge", "Histogram", "MetricsRegistry",
     "NULL_TRACER", "NullTracer", "Telemetry", "TelemetrySpec", "TraceEvent",
     "Tracer", "chrome_trace", "chrome_tracks", "configure_logging",
-    "device_time",
+    "device_time", "host",
     "events_from_chrome", "get_logger", "load_chrome_trace", "read_jsonl",
     "write_chrome_trace", "write_jsonl",
 ]

@@ -120,6 +120,16 @@ class MetricsRegistry:
                     out.add(v)
         return sorted(out)
 
+    def counter_values(self) -> Dict[str, float]:
+        """Every counter's value, keyed ``name{label=value,...}``."""
+        return {_render(k): c.value for k, c in self._counters.items()}
+
+    def histogram_samples(self) -> Dict[str, List[float]]:
+        """A copy of every histogram's samples, keyed like
+        `counter_values`."""
+        return {_render(k): list(h.samples)
+                for k, h in self._histograms.items()}
+
     def snapshot(self) -> Dict[str, Any]:
         """JSON-ready dump: counters/gauges as scalars, histograms as
         summary dicts, keys rendered ``name{label=value,...}``."""

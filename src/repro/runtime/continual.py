@@ -63,6 +63,7 @@ import numpy as np
 
 from repro.data.arrivals import Event, build_timeline
 from repro.data.streams import ContinualBenchmark
+from repro.obs.host import span
 from repro.obs.trace import NULL_TRACER
 from repro.optim import AdamWConfig
 from repro.runtime.config import (DeviceConfig, HookSpec, RuntimeConfig,
@@ -111,6 +112,10 @@ class RunResult:
     syncs: int = 0
     # detector mode: drift-confirmation probe passes fired
     probes: int = 0
+    # host spans and counters of this run (repro.obs.host.since): real
+    # seconds, not deterministic, so out of equality and summary()
+    host: Dict[str, Any] = field(default_factory=dict, compare=False,
+                                 repr=False)
 
     def summary(self) -> str:
         return (f"acc={self.avg_inference_acc*100:.2f}% "
@@ -433,4 +438,5 @@ def edgeol_session(cfg: RuntimeConfig, **inject) -> ContinualRuntime:
 
         res = edgeol_session(RuntimeConfig(workload="mixed", ...)).run()
     """
-    return ContinualRuntime.from_config(cfg, **inject)
+    with span("build"):
+        return ContinualRuntime.from_config(cfg, **inject)

@@ -27,16 +27,16 @@ from __future__ import annotations
 
 from typing import Dict, List
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.data.arrivals import Event
+from repro.obs.host import count, span
 from repro.runtime.costmodel import scale_cost
 from repro.runtime.executor import FineTuneExecutor, ReplayBuffer
 from repro.runtime.inference import InferenceServer
 from repro.runtime.modelpool import ModelPool, ModelSlot, tree_mb
-from repro.runtime.train_loop import as_jnp, evaluate
+from repro.runtime.train_loop import (EVALUATE_PULLS, as_jnp, copy_tree,
+                                      evaluate)
 
 
 class DeviceRuntime:
@@ -141,24 +141,28 @@ class DeviceRuntime:
         stream = report.stream
         ctrl = fleet.ctrl_for(stream)
         pub = getattr(ctrl, "publish_policy", None)
-        if pub is None:
-            self.server.publish(slot.executor.params, report.end,
-                                slot=slot.name)
-        else:
-            self.server.publish(slot.executor.params,
-                                pub.visible_at(report.end), slot=slot.name,
-                                delayed=pub.delayed)
+        with span("round/publish"):
+            if pub is None:
+                self.server.publish(slot.executor.params, report.end,
+                                    slot=slot.name)
+            else:
+                self.server.publish(slot.executor.params,
+                                    pub.visible_at(report.end), slot=slot.name,
+                                    delayed=pub.delayed)
         # validation accuracy (labeled 5% split) -> LazyTune; the
         # split belongs to the scenario current at round *launch*
         val = fleet.bench_for(stream).scenarios[
             fleet.launch_scenario.pop(
                 stream, self.scheduler.scenario_of(stream))].val
-        val_acc, _ = evaluate(slot.model, slot.executor.params,
-                              as_jnp(val))
+        with span("round/validate"):
+            val_acc, _ = evaluate(slot.model, slot.executor.params,
+                                  as_jnp(val))
+        count("host_syncs", EVALUATE_PULLS, site="validate")
         fleet.val_curve.append(val_acc)
         cka_before = ctrl.simfreeze.state.cka_flops \
             if hasattr(ctrl, "simfreeze") else 0.0
-        ctrl.round_finished(report.iters, val_acc, slot.executor.params)
+        with span("round/policy"):
+            ctrl.round_finished(report.iters, val_acc, slot.executor.params)
         if hasattr(ctrl, "simfreeze"):
             dcka = ctrl.simfreeze.state.cka_flops - cka_before
             if dcka:
@@ -228,16 +232,17 @@ class DeviceRuntime:
     def finish_round(self, now: float, stream: int = 0) -> None:
         fleet = self.fleet
         slot = self.slot_of(stream)
-        self.acquire(slot, now, stream)
-        fleet.launch_scenario[stream] = self.scheduler.scenario_of(stream)
-        report = slot.executor.execute_round(
-            fleet.ctrl_for(stream).plan, now, self.scheduler, stream=stream,
-            priority=fleet.stream_priority.get(stream, 0),
-            preemptible=self.host.preemptible)
-        if report is None and slot.executor.active_round is None:
-            fleet.launch_scenario.pop(stream, None)  # nothing was buffered
-        elif report is not None:  # synchronous (non-preemptible) path
-            self.complete(slot, report)
+        with span("round"):
+            self.acquire(slot, now, stream)
+            fleet.launch_scenario[stream] = self.scheduler.scenario_of(stream)
+            report = slot.executor.execute_round(
+                fleet.ctrl_for(stream).plan, now, self.scheduler, stream=stream,
+                priority=fleet.stream_priority.get(stream, 0),
+                preemptible=self.host.preemptible)
+            if report is None and slot.executor.active_round is None:
+                fleet.launch_scenario.pop(stream, None)  # nothing was buffered
+            elif report is not None:  # synchronous (non-preemptible) path
+                self.complete(slot, report)
 
     # ---- event handlers (fleet settles every device first) ---------------
     def on_scenario_change(self, previous: int, ev: Event) -> None:
@@ -337,6 +342,7 @@ class DeviceRuntime:
                                  1), len(b.scenarios) - 1)]
         _, logits = evaluate(slot.model, slot.executor.params,
                              as_jnp(sc.val))
+        count("host_syncs", EVALUATE_PULLS, site="probe")
         flops = slot.steps.flops(ctrl.plan,
                                  as_jnp(sc.train_batches[0])) / 3.0
         tc, ec = slot.executor.cost.compute_cost(flops)
@@ -403,8 +409,8 @@ def clone_device_slots(fleet, spec, index: int, slots0: Dict,
             preempt_resume_cost_s=host.preempt_resume_cost_s,
             compiled=host.compiled, fuse=host.segment,
             tracer=fleet.tracer)
-        executor.load(jax.tree.map(jnp.copy, src.executor.params),
-                      jax.tree.map(jnp.copy, src.executor.opt_state))
+        executor.load(copy_tree(src.executor.params, "clone"),
+                      copy_tree(src.executor.opt_state, "clone"))
         slots[name] = _SlotState(name, src.model, src.bench, ctrl,
                                  src.steps, executor,
                                  reference_params=src.reference_params)

@@ -17,7 +17,6 @@ controller notification stay in the composition root
 from __future__ import annotations
 
 import dataclasses
-import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence
 
@@ -25,10 +24,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.host import span
 from repro.obs.trace import NULL_TRACER
 from repro.runtime.costmodel import EdgeCostModel
 from repro.runtime.ledger import DEFAULT_DEVICE, DEFAULT_MODEL, CostLedger
-from repro.runtime.train_loop import (TrainStepCache, as_jnp,
+from repro.runtime.train_loop import (TrainStepCache, as_jnp, copy_tree,
                                       same_shape_runs)
 
 
@@ -233,8 +233,8 @@ class FineTuneExecutor:
         self.rng = rng
         # observability (DESIGN.md §14): a live Tracer records round /
         # segment / resume spans on the modeled timeline, annotated with
-        # wall-clock training time and recompiles; the falsy NULL_TRACER
-        # default keeps every guarded site allocation-free.
+        # recompiles; the falsy NULL_TRACER default keeps every guarded
+        # site allocation-free.
         self.tracer = tracer
         self.hooks = list(hooks)
         self.calibrate_cost = calibrate_cost
@@ -299,8 +299,9 @@ class FineTuneExecutor:
         already owns its (freshly produced) buffers. One device copy per
         round, bitwise identical."""
         if getattr(self.steps, "donate", False):
-            self.params = jax.tree.map(jnp.copy, self.params)
-            self.opt_state = jax.tree.map(jnp.copy, self.opt_state)
+            with span("round/own_buffers"):
+                self.params = copy_tree(self.params, "own_buffers")
+                self.opt_state = copy_tree(self.opt_state, "own_buffers")
 
     def _train_batch(self, step, plan, b: dict) -> None:
         """One training iteration: the first hook that claims the batch
@@ -335,18 +336,19 @@ class FineTuneExecutor:
 
     def _round_cost(self, plan, batches, recompile: int):
         """XLA-measured round FLOPs + (one-shot calibrated) modeled cost."""
-        flops = self.steps.flops(plan, as_jnp(batches[0])) * len(batches)
-        if self.calibrate_cost:
-            # Preserve the paper's compute/overhead balance (Fig. 3) at
-            # reduced model scale: scale the device throughput so a
-            # 2-iteration immediate round spends ~0.8 s in compute vs the
-            # 1.1 s fixed overheads (58%/42% split). DESIGN.md §3.
-            per_iter = flops / max(len(batches), 1)
-            self.cost = dataclasses.replace(
-                self.cost,
-                flops_per_sec=max(per_iter * 2 / 0.8, 1.0) * self.speed_scale)
-            self.calibrate_cost = False
-        t, e, parts = self.cost.round_cost(flops, recompiles=recompile)
+        with span("round/cost"):
+            flops = self.steps.flops(plan, as_jnp(batches[0])) * len(batches)
+            if self.calibrate_cost:
+                # Preserve the paper's compute/overhead balance (Fig. 3) at
+                # reduced model scale: scale the device throughput so a
+                # 2-iteration immediate round spends ~0.8 s in compute vs the
+                # 1.1 s fixed overheads (58%/42% split). DESIGN.md §3.
+                per_iter = flops / max(len(batches), 1)
+                self.cost = dataclasses.replace(
+                    self.cost,
+                    flops_per_sec=max(per_iter * 2 / 0.8, 1.0) * self.speed_scale)
+                self.calibrate_cost = False
+            t, e, parts = self.cost.round_cost(flops, recompiles=recompile)
         return flops, t, e, parts
 
     def estimate_round(self, plan, stream: int = 0):
@@ -403,10 +405,7 @@ class FineTuneExecutor:
             h.on_round_start(self.ledger.rounds)
         if not preemptible:
             # legacy synchronous path — bit-exact with the pre-QoS runtime
-            wall = time.perf_counter() if self.tracer else 0.0
             self._run_batches(step, plan, batches)
-            if self.tracer:
-                wall = time.perf_counter() - wall
             flops, t, e, parts = self._round_cost(plan, batches, recompile)
             self.ledger.charge_round(flops=flops, time_s=t, energy_j=e,
                                      parts=parts, stream=stream,
@@ -420,8 +419,7 @@ class FineTuneExecutor:
                                  start, t, stream=stream,
                                  device=self.device_name,
                                  slot=self.model_name, iters=len(batches),
-                                 recompiled=bool(recompile),
-                                 wall_ms=round(wall * 1e3, 3))
+                                 recompiled=bool(recompile))
             return RoundReport(iters=len(batches), flops=flops, time_s=t,
                                energy_j=e, recompiled=bool(recompile),
                                start=start, end=end, stream=stream)

@@ -43,6 +43,7 @@ from repro.core.policies import adapt_controller
 from repro.data.arrivals import Event
 from repro.distributed.straggler import StragglerConfig, StragglerTracker
 from repro.env import DeviceEnv, EnvLedgerObserver
+from repro.obs.host import since, snapshot, span
 from repro.obs.log import get_logger
 from repro.obs.trace import NULL_TRACER
 from repro.runtime.config import DeviceConfig
@@ -51,8 +52,8 @@ from repro.runtime.device import (DeviceRuntime, clone_device_slots,
 from repro.runtime.ledger import (DEFAULT_DEVICE, DEVICE_KEYS, MODEL_KEYS,
                                   STREAM_KEYS, CostLedger)
 from repro.runtime.scheduler import EventScheduler
-from repro.runtime.train_loop import (as_jnp, make_optimizer_state,
-                                      same_shape_runs)
+from repro.runtime.train_loop import (as_jnp, copy_tree,
+                                      make_optimizer_state, same_shape_runs)
 
 #: Pseudo-stream id cross-device sync charges land on: no arrival stream
 #: caused them, the fleet did. Appears in `per_stream` like any stream
@@ -215,6 +216,9 @@ class DeviceFleet:
         from repro.runtime.continual import RunResult
 
         host = self.host
+        # host spans and counters up to now; _assemble keeps this run's
+        # delta as RunResult.host
+        self._host_mark = snapshot()
         rng = np.random.default_rng(host.seed)
         ledger = CostLedger()
         self.ledger = ledger
@@ -236,30 +240,32 @@ class DeviceFleet:
         # --- pretrain every slot on its scenario 0 (not cost-accounted;
         # paper §V-A) and measure slot memory footprints — once, centrally:
         # every fleet device starts from the same pretrained model --------
-        for st in slots0.values():
-            params = st.model.init(jax.random.PRNGKey(host.seed))
-            opt_state = make_optimizer_state(st.model, host.opt_cfg, params)
-            if st.steps.donate:
-                # donation needs de-aliased buffers: init trees share
-                # zero-filled leaves (and constant-cache hits), which a
-                # donating step would otherwise donate twice
-                params = jax.tree.map(jnp.copy, params)
-                opt_state = jax.tree.map(jnp.copy, opt_state)
-            plan0 = st.controller.plan
-            pre = [b for _ in range(host.pretrain_epochs)
-                   for b in st.bench.scenarios[0].train_batches]
-            if host.compiled:
-                # one fused scan per same-shape run of pretrain batches
-                for run in same_shape_runs(pre):
-                    params, opt_state, _ = st.steps.fused_call(
-                        plan0, params, opt_state, run)
-            else:
-                step0 = st.steps.get(plan0)
-                for b in pre:
-                    params, opt_state, _ = step0(params, opt_state,
-                                                 as_jnp(b))
-            st.reference_params = params  # "initial model before fine-tuning"
-            st.executor.load(params, opt_state)
+        with span("pretrain"):
+            for st in slots0.values():
+                params = st.model.init(jax.random.PRNGKey(host.seed))
+                opt_state = make_optimizer_state(st.model, host.opt_cfg, params)
+                if st.steps.donate:
+                    # donation needs de-aliased buffers: init trees share
+                    # zero-filled leaves (and constant-cache hits), which a
+                    # donating step would otherwise donate twice
+                    params = copy_tree(params, "pretrain")
+                    opt_state = copy_tree(opt_state, "pretrain")
+                plan0 = st.controller.plan
+                pre = [b for _ in range(host.pretrain_epochs)
+                       for b in st.bench.scenarios[0].train_batches]
+                if host.compiled:
+                    # one fused scan per same-shape run of pretrain batches
+                    for run in same_shape_runs(pre):
+                        params, opt_state, _ = st.steps.fused_call(
+                            plan0, params, opt_state, run)
+                else:
+                    step0 = st.steps.get(plan0)
+                    for b in pre:
+                        params, opt_state, _ = step0(params, opt_state,
+                                                     as_jnp(b))
+                # "initial model before fine-tuning"
+                st.reference_params = params
+                st.executor.load(params, opt_state)
         if host.pool is not None:
             from repro.runtime.modelpool import tree_mb
 
@@ -357,43 +363,52 @@ class DeviceFleet:
 
         # --- drive the shared timeline ------------------------------------
         def on_data(ev: Event, boundary: bool) -> None:
-            self._advance(ev.time)
-            self._settle_all(ev.time)
-            if self.envs:
-                self._step_envs(ev.time)
-            self.device_for(ev.stream).on_data(ev, boundary)
+            with span("event/data"):
+                self._advance(ev.time)
+                self._settle_all(ev.time)
+                if self.envs:
+                    self._step_envs(ev.time)
+                self.device_for(ev.stream).on_data(ev, boundary)
 
         def on_scenario_change(previous: int, ev: Event) -> None:
-            self.device_for(ev.stream).on_scenario_change(previous, ev)
+            with span("event/scenario"):
+                self.device_for(ev.stream).on_scenario_change(previous, ev)
 
-        def on_inference(ev: Event) -> None:
+        def inference(ev: Event) -> None:
             self._advance(ev.time)
             self._settle_all(ev.time)
             if self.envs:
                 self._step_envs(ev.time)
             self.device_for(ev.stream).on_inference(ev)
 
+        def on_inference(ev: Event) -> None:
+            with span("event/inference"):
+                inference(ev)
+
         def on_inference_event(ev: Event) -> None:
             # compiled but unsegmented (detector mode, or `segment` off):
             # serve each event's deferred dispatch before the next event
-            on_inference(ev)
-            self.device_for(ev.stream).server.drain()
+            with span("event/inference"):
+                inference(ev)
+                self.device_for(ev.stream).server.drain()
 
         def on_probe(ev: Event) -> None:
-            self._advance(ev.time)
-            self._settle_all(ev.time)
-            if self.envs:
-                self._step_envs(ev.time)
-            self.device_for(ev.stream).on_probe(ev)
+            with span("event/probe"):
+                self._advance(ev.time)
+                self._settle_all(ev.time)
+                if self.envs:
+                    self._step_envs(ev.time)
+                self.device_for(ev.stream).on_probe(ev)
 
         def on_inference_segment(segment: List[Event]) -> None:
             # a maximal run of consecutive inference events (compiled hot
             # path, DESIGN.md §12): per-event bookkeeping is unchanged,
             # only each device's dispatch is deferred and fused per drain
-            for ev in segment:
-                on_inference(ev)
-            for dev in self.devices:
-                dev.server.drain()
+            with span("event/segment"):
+                for ev in segment:
+                    on_inference(ev)
+                for dev in self.devices:
+                    dev.server.drain()
 
         segmented = (host.compiled and host.segment
                      and host.boundaries != "detector")
@@ -404,11 +419,12 @@ class DeviceFleet:
             on_scenario_change=on_scenario_change, on_probe=on_probe,
             on_inference_segment=on_inference_segment if segmented
             else None)
-        self._settle_all(float("inf"))  # finalize rounds still in flight
-        for dev in self.devices:
-            dev.server.flush()
-            dev.server.drain()
-            dev.trailing_flush()
+        with span("flush"):
+            self._settle_all(float("inf"))  # finalize rounds in flight
+            for dev in self.devices:
+                dev.server.flush()
+                dev.server.drain()
+                dev.trailing_flush()
 
         return self._assemble(RunResult)
 
@@ -686,4 +702,5 @@ class DeviceFleet:
             per_model=per_model, per_device=per_device,
             preemptions=ledger.preemptions,
             swaps=ledger.swaps, syncs=ledger.syncs,
-            probes=self.probes_fired[0])
+            probes=self.probes_fired[0],
+            host=since(self._host_mark))

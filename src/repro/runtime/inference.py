@@ -38,14 +38,16 @@ pins down.
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
+from repro.obs.host import count, observe, span
 from repro.obs.trace import NULL_TRACER
 from repro.runtime.ledger import DEFAULT_MODEL
-from repro.runtime.train_loop import as_jnp, evaluate
+from repro.runtime.train_loop import EVALUATE_PULLS, as_jnp, evaluate
 
 # Process-global serving programs: jit(vmap(predict)) keyed on the
 # predict closure itself plus (concat-signature, stack bucket), so every
@@ -72,6 +74,9 @@ class _Pending:
     stream: int = 0  # arrival stream (multi-stream workloads)
     slot: str = DEFAULT_MODEL  # model slot that serves it (ModelPool)
     model: Any = field(default=None, repr=False)
+    # host clock at submit (perf_counter): `request_wait_s` runs from here
+    # to the dispatch of the forward that answers the request
+    submitted: float = field(default=0.0, repr=False, compare=False)
 
 
 class InferenceServer:
@@ -209,21 +214,23 @@ class InferenceServer:
         `latency` is the caller-computed serving latency (arrival ->
         modeled service time); it is recorded per stream and reported via
         `RunResult.per_stream` percentiles, never acted on here."""
-        self.latencies_by_stream.setdefault(stream, []).append(float(latency))
-        if self.tracer:
-            self.tracer.span("request", f"s{stream}", t, float(latency),
-                             stream=stream, slot=slot)
-        params = self._resolve(t, slot)
-        pending = _Pending(t, request, params, stream, slot,
-                           self._lanes[slot].model)
-        if self.batch_window <= 0.0:
-            self._serve([pending])
-            return
-        if self._queue and (t - self._queue[0].time > self.batch_window
-                            or self._queue[0].params is not params
-                            or self._queue[0].slot != slot):
-            self.flush()
-        self._queue.append(pending)
+        with span("serve/submit"):
+            self.latencies_by_stream.setdefault(stream, []).append(
+                float(latency))
+            if self.tracer:
+                self.tracer.span("request", f"s{stream}", t, float(latency),
+                                 stream=stream, slot=slot)
+            params = self._resolve(t, slot)
+            pending = _Pending(t, request, params, stream, slot,
+                               self._lanes[slot].model, time.perf_counter())
+            if self.batch_window <= 0.0:
+                self._serve([pending])
+                return
+            if self._queue and (t - self._queue[0].time > self.batch_window
+                                or self._queue[0].params is not params
+                                or self._queue[0].slot != slot):
+                self.flush()
+            self._queue.append(pending)
 
     def flush(self) -> None:
         if self._queue:
@@ -257,9 +264,12 @@ class InferenceServer:
                                 group[0].time, device=self.track,
                                 slot=group[0].slot, requests=len(group))
         self.eval_calls += 1
+        _waited(group)
+        count("host_syncs", EVALUATE_PULLS, site="serve")
         if len(group) == 1:
             p = group[0]
-            acc, logits = evaluate(p.model, p.params, as_jnp(p.request))
+            with span("serve/forward"):
+                acc, logits = evaluate(p.model, p.params, as_jnp(p.request))
             self._record(p, acc, logits)
             return
         # one forward pass over the concatenated group, then per-request
@@ -267,7 +277,9 @@ class InferenceServer:
         # request in a group shares the same params (and hence model).
         batch = {k: np.concatenate([p.request[k] for p in group])
                  for k in group[0].request}
-        _, logits = evaluate(group[0].model, group[0].params, as_jnp(batch))
+        with span("serve/forward"):
+            _, logits = evaluate(group[0].model, group[0].params,
+                                 as_jnp(batch))
         offset = 0
         for p in group:
             n = len(p.request["labels"])
@@ -289,20 +301,26 @@ class InferenceServer:
         Results are recorded strictly in arrival order."""
         if not self._ready:
             return
+        with span("serve/drain"):
+            self._drain()
+
+    def _drain(self) -> None:
         ready, self._ready = self._ready, []
         concats: List[Dict[str, np.ndarray]] = []
         stacks: Dict[Any, List[int]] = {}
-        for gi, group in enumerate(ready):
-            if len(group) == 1:
-                batch = {k: np.asarray(v) for k, v in group[0].request.items()}
-            else:
-                batch = {k: np.concatenate([p.request[k] for p in group])
-                         for k in group[0].request}
-            concats.append(batch)
-            sig = tuple(sorted((k, v.shape, str(v.dtype))
-                               for k, v in batch.items()))
-            key = (group[0].slot, id(group[0].params), sig)
-            stacks.setdefault(key, []).append(gi)
+        with span("serve/stage"):
+            for gi, group in enumerate(ready):
+                if len(group) == 1:
+                    batch = {k: np.asarray(v)
+                             for k, v in group[0].request.items()}
+                else:
+                    batch = {k: np.concatenate([p.request[k] for p in group])
+                             for k in group[0].request}
+                concats.append(batch)
+                sig = tuple(sorted((k, v.shape, str(v.dtype))
+                                   for k, v in batch.items()))
+                key = (group[0].slot, id(group[0].params), sig)
+                stacks.setdefault(key, []).append(gi)
         logits_by_group: Dict[int, np.ndarray] = {}
         for (slot, _, sig), idxs in stacks.items():
             first = ready[idxs[0]][0]
@@ -313,24 +331,27 @@ class InferenceServer:
                                     requests=sum(len(ready[i])
                                                  for i in idxs))
             out = self._forward_stack(first.model, first.params, slot, sig,
-                                      [concats[i] for i in idxs])
+                                      [concats[i] for i in idxs],
+                                      [p for i in idxs for p in ready[i]])
             for row, gi in enumerate(idxs):
                 logits_by_group[gi] = out[row]
-        for gi, group in enumerate(ready):
-            self.eval_calls += 1
-            logits = logits_by_group[gi]
-            offset = 0
-            for p in group:
-                n = len(p.request["labels"])
-                lg = logits[offset:offset + n]
-                offset += n
-                acc = float(np.mean((np.argmax(lg, -1) ==
-                                     np.asarray(p.request["labels"]))
-                                    .astype(np.float32)))
-                self._record(p, acc, lg)
+        with span("serve/score"):
+            for gi, group in enumerate(ready):
+                self.eval_calls += 1
+                logits = logits_by_group[gi]
+                offset = 0
+                for p in group:
+                    n = len(p.request["labels"])
+                    lg = logits[offset:offset + n]
+                    offset += n
+                    acc = float(np.mean((np.argmax(lg, -1) ==
+                                         np.asarray(p.request["labels"]))
+                                        .astype(np.float32)))
+                    self._record(p, acc, lg)
 
     def _forward_stack(self, model, params, slot, sig,
-                       concats: List[Dict[str, np.ndarray]]) -> np.ndarray:
+                       concats: List[Dict[str, np.ndarray]],
+                       waiting: List[_Pending]) -> np.ndarray:
         import jax
         import jax.numpy as jnp
 
@@ -341,10 +362,16 @@ class InferenceServer:
         if fwd is None:
             fwd = _VMAPPED[key] = jax.jit(
                 jax.vmap(model.predict, in_axes=(None, 0)))
-        stacked = {k: jnp.stack([jnp.asarray(c[k]) for c in concats]
-                                + [jnp.asarray(concats[0][k])] * (bucket - n))
-                   for k in concats[0]}
-        return np.asarray(fwd(params, stacked))[:n]
+        with span("serve/stage"):
+            stacked = {k: jnp.stack([jnp.asarray(c[k]) for c in concats]
+                                    + [jnp.asarray(concats[0][k])]
+                                    * (bucket - n))
+                       for k in concats[0]}
+        _waited(waiting)
+        with span("serve/forward"):
+            out = np.asarray(fwd(params, stacked))[:n]
+        count("host_syncs", 1, site="serve")
+        return out
 
     def _record(self, p: _Pending, acc: float, logits) -> None:
         self.accs.append(acc)
@@ -358,3 +385,11 @@ class InferenceServer:
     @property
     def avg_acc(self) -> float:
         return float(np.mean(self.accs)) if self.accs else 0.0
+
+
+def _waited(requests: List[_Pending]) -> None:
+    """Each request's host wait from submit to now, the dispatch of the
+    forward that answers it (`request_wait_s`)."""
+    now = time.perf_counter()
+    for p in requests:
+        observe("request_wait_s", now - p.submitted)

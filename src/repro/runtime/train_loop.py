@@ -25,6 +25,7 @@ from typing import Any, Callable, Dict, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs.host import count, span
 from repro.optim import (AdamWConfig, adamw_init, adamw_update,
                          sgdm_init, sgdm_update)
 
@@ -167,11 +168,13 @@ class TrainStepCache:
         self.get(plan, batches[0])  # recompile-ledger bookkeeping
         fn, bucket = self.multi_step(plan, batches[0], len(batches))
         pad = bucket - len(batches)
-        stacked = {k: jnp.stack([jnp.asarray(b[k]) for b in batches]
-                                + [jnp.asarray(batches[0][k])] * pad)
-                   for k in batches[0]}
-        valid = jnp.arange(bucket) < len(batches)
-        return fn(params, opt_state, stacked, valid)
+        with span("train/stage"):
+            stacked = {k: jnp.stack([jnp.asarray(b[k]) for b in batches]
+                                    + [jnp.asarray(batches[0][k])] * pad)
+                       for k in batches[0]}
+            valid = jnp.arange(bucket) < len(batches)
+        with span("train/dispatch"):
+            return fn(params, opt_state, stacked, valid)
 
     def flops(self, plan, example_batch) -> float:
         """XLA-measured FLOPs of one train step under `plan` (compiled once,
@@ -209,6 +212,14 @@ def same_shape_runs(batches: Sequence[dict]):
         i = j
 
 
+def copy_tree(tree, site: str):
+    """Leaf-by-leaf device copy of `tree` (what a donating step may
+    consume), counted as `device_copies{site}`."""
+    leaves, treedef = jax.tree.flatten(tree)
+    count("device_copies", len(leaves), site=site)
+    return treedef.unflatten([jnp.copy(x) for x in leaves])
+
+
 def as_jnp(batch: dict) -> dict:
     """Host batch dict -> device arrays (shared by training and serving)."""
     return {k: jnp.asarray(v) for k, v in batch.items()}
@@ -241,8 +252,14 @@ def compiled_model(model):
     return wrapped
 
 
+#: device-to-host pulls of one `evaluate`: the accuracy and the logits
+EVALUATE_PULLS = 2
+
+
 def evaluate(model, params, batch) -> Tuple[float, Any]:
-    """Returns (accuracy, logits) on a labeled batch."""
+    """Returns (accuracy, logits) on a labeled batch: two device-to-host
+    pulls (`EVALUATE_PULLS`), which each caller counts as its
+    `host_syncs{site}`."""
     logits = model.predict(params, batch) if model.predict is not None else None
     if logits is None:
         raise ValueError("model has no predict()")
