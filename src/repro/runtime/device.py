@@ -409,8 +409,8 @@ def clone_device_slots(fleet, spec, index: int, slots0: Dict,
             preempt_resume_cost_s=host.preempt_resume_cost_s,
             compiled=host.compiled, fuse=host.segment,
             tracer=fleet.tracer)
-        executor.load(copy_tree(src.executor.params, "clone"),
-                      copy_tree(src.executor.opt_state, "clone"))
+        executor.load(*copy_tree(
+            (src.executor.params, src.executor.opt_state), "clone"))
         slots[name] = _SlotState(name, src.model, src.bench, ctrl,
                                  src.steps, executor,
                                  reference_params=src.reference_params)

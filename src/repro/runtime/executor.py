@@ -294,14 +294,16 @@ class FineTuneExecutor:
         """Donating steps consume their inputs. Params escape the
         executor between rounds — serving lanes hold the published
         object, `reference_params` is the pretrain result — so before a
-        round's first donating dispatch we take exclusive copies; the
-        escaped aliases stay live and every later dispatch in the round
-        already owns its (freshly produced) buffers. One device copy per
-        round, bitwise identical."""
+        round's first donating dispatch we take an exclusive copy of
+        them, in one copy program; the escaped aliases stay live and
+        every later dispatch in the round already owns its (freshly
+        produced) buffers. The optimizer state never leaves the executor
+        and arrives de-aliased (`load` takes a pretraining scan's output
+        or a copy), so it needs no copy: each fresh buffer costs host
+        time at dispatch whatever its size."""
         if getattr(self.steps, "donate", False):
             with span("round/own_buffers"):
                 self.params = copy_tree(self.params, "own_buffers")
-                self.opt_state = copy_tree(self.opt_state, "own_buffers")
 
     def _train_batch(self, step, plan, b: dict) -> None:
         """One training iteration: the first hook that claims the batch

@@ -248,8 +248,8 @@ class DeviceFleet:
                     # donation needs de-aliased buffers: init trees share
                     # zero-filled leaves (and constant-cache hits), which a
                     # donating step would otherwise donate twice
-                    params = copy_tree(params, "pretrain")
-                    opt_state = copy_tree(opt_state, "pretrain")
+                    params, opt_state = copy_tree((params, opt_state),
+                                                  "pretrain")
                 plan0 = st.controller.plan
                 pre = [b for _ in range(host.pretrain_epochs)
                        for b in st.bench.scenarios[0].train_batches]
@@ -526,7 +526,7 @@ class DeviceFleet:
                              / total).astype(ls[0].dtype), *trees)
             for d in group:
                 ex = d.slots[name].executor
-                ex.params = jax.tree.map(jnp.copy, merged)
+                ex.params = copy_tree(merged, "merge")
                 d.server.publish(ex.params, ts, slot=name)
                 c = ex.cost
                 t_sync = c.t_save_s + c.t_load_s

@@ -212,12 +212,23 @@ def same_shape_runs(batches: Sequence[dict]):
         i = j
 
 
+@jax.jit
+def _copy_leaves(tree):
+    # `jnp.copy` binds `copy_p`, so no output is forwarded from an input:
+    # XLA writes every leaf to a fresh buffer, aliased inputs included.
+    return jax.tree.map(jnp.copy, tree)
+
+
 def copy_tree(tree, site: str):
-    """Leaf-by-leaf device copy of `tree` (what a donating step may
-    consume), counted as `device_copies{site}`."""
-    leaves, treedef = jax.tree.flatten(tree)
-    count("device_copies", len(leaves), site=site)
-    return treedef.unflatten([jnp.copy(x) for x in leaves])
+    """Device copy of `tree` (what a donating step may consume) in one
+    dispatch of a jitted copy program, one per tree structure and avals.
+    Every output leaf is a fresh buffer, distinct from every input and
+    from every other output even where input leaves alias one another;
+    the values are bitwise the inputs'. Counted as `device_copies{site}`
+    (leaves) and `copy_programs{site}` (dispatches)."""
+    count("device_copies", len(jax.tree.leaves(tree)), site=site)
+    count("copy_programs", site=site)
+    return _copy_leaves(tree)
 
 
 def as_jnp(batch: dict) -> dict:

@@ -16,11 +16,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import Model
+from repro.obs import host
 from repro.optim import AdamWConfig
 from repro.runtime import RuntimeConfig, SlotConfig, edgeol_session
 from repro.runtime.train_loop import (TrainStepCache, as_jnp,
-                                      batch_signature, make_optimizer_state,
-                                      same_shape_runs)
+                                      batch_signature, copy_tree,
+                                      make_optimizer_state, same_shape_runs)
 
 SCALE = dict(batches_per_scenario=3, inferences=6, num_scenarios=2)
 
@@ -123,6 +124,59 @@ def test_donated_step_bitwise_matches_undonated():
         results.append(_leaves(params) + _leaves(opt_state))
     for a, b in zip(*results):
         np.testing.assert_array_equal(a, b)
+
+
+def _aliased_tree(seed):
+    """Params and Adam state as init builds them: the moments share one
+    zero buffer, and a second parameter aliases the first."""
+    w = jax.random.normal(jax.random.PRNGKey(seed), (4, 2))
+    zeros = jnp.zeros((4, 2))
+    return ({"w": w, "v": w}, {"mu": zeros, "nu": zeros,
+                               "count": jnp.zeros([], jnp.int32)})
+
+
+def test_copy_tree_gives_fresh_buffers_for_aliased_leaves():
+    tree = _aliased_tree(0)
+    out = copy_tree(tree, "test")
+    assert jax.tree.structure(out) == jax.tree.structure(tree)
+    ins, outs = jax.tree.leaves(tree), jax.tree.leaves(out)
+    for a, b in zip(ins, outs):
+        assert (a.dtype, a.shape) == (b.dtype, b.shape)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    in_ptrs = {l.unsafe_buffer_pointer() for l in ins}
+    out_ptrs = [l.unsafe_buffer_pointer() for l in outs]
+    assert len(in_ptrs) < len(ins)  # the input does alias
+    assert len(set(out_ptrs)) == len(outs)
+    assert not in_ptrs & set(out_ptrs)
+
+
+def test_copy_tree_is_one_program_per_tree_structure():
+    copy_tree(_aliased_tree(1), "test")
+    tree = _aliased_tree(2)
+    mark = host.snapshot()
+    with host.span("copy_again"):
+        out = copy_tree(tree, "test")
+    counters = host.since(mark)["counters"]
+    assert not [k for k in counters if k.startswith("compiles")]
+    assert counters["copy_programs{site=test}"] == 1
+    assert counters["device_copies{site=test}"] == len(jax.tree.leaves(out))
+
+
+def test_session_leaves_no_aliased_train_state():
+    """Rounds copy only the params (what escapes the executor); the
+    optimizer state they donate uncopied must never share a buffer with
+    another leaf of the train state."""
+    cfg = RuntimeConfig(slots={"cv": SlotConfig()}, workload="single-poisson",
+                        workload_scale=dict(SCALE), seed=0,
+                        pretrain_epochs=1, compiled=True)
+    rt = edgeol_session(cfg)
+    assert rt.run().rounds > 0
+    for dev in rt.fleet.devices:
+        for slot in dev.slots.values():
+            ex = slot.executor
+            ptrs = [l.unsafe_buffer_pointer()
+                    for l in jax.tree.leaves((ex.params, ex.opt_state))]
+            assert len(set(ptrs)) == len(ptrs)
 
 
 def test_fused_scan_bitwise_matches_single_steps():

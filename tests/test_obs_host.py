@@ -14,6 +14,7 @@ from jax._src.array import ArrayImpl
 
 from repro.obs import host
 from repro.runtime import RuntimeConfig, SlotConfig, edgeol_session
+from repro.runtime.train_loop import make_optimizer_state
 
 SCALE = dict(batches_per_scenario=3, inferences=6, num_scenarios=2)
 
@@ -57,8 +58,12 @@ def runs():
     t0 = time.perf_counter()
     second = rt.run()
     wall = time.perf_counter() - t0
+    params = jax.eval_shape(rt.model.init, jax.random.PRNGKey(0))
+    state = jax.eval_shape(
+        lambda p: make_optimizer_state(rt.model, rt.opt_cfg, p), params)
     return {"first": first, "second": second, "pulls": pulls[0],
-            "wall": wall}
+            "wall": wall, "param_leaves": len(jax.tree.leaves(params)),
+            "state_leaves": len(jax.tree.leaves(state))}
 
 
 def test_spans_nest_by_path_with_self_time():
@@ -141,6 +146,19 @@ def test_run_result_host_is_a_per_run_delta(runs):
             "event/inference", "serve/submit", "serve/drain", "serve/stage",
             "serve/forward", "serve/score", "cka/reference", "cka/pass",
             "cka/features"} <= names
+
+
+def test_one_copy_program_per_round_and_per_pretraining(runs):
+    """A donating round owns the params (what escapes the executor)
+    through one copy program; pretraining de-aliases params and optimizer
+    state through one. `device_copies` counts every leaf copied."""
+    n_params, n_state = runs["param_leaves"], runs["state_leaves"]
+    for r in (runs["first"], runs["second"]):
+        c = r.host["counters"]
+        assert c["copy_programs{site=own_buffers}"] == r.rounds > 0
+        assert c["device_copies{site=own_buffers}"] == r.rounds * n_params
+        assert c["copy_programs{site=pretrain}"] == 1
+        assert c["device_copies{site=pretrain}"] == n_params + n_state
 
 
 def test_host_field_stays_out_of_equality_and_summary(runs):
